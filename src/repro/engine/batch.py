@@ -256,6 +256,38 @@ def shard_perms(kg: ShardedKG) -> np.ndarray:
     return perms
 
 
+def _select_windows(n, width: int, cap: int):
+    """`select_cap` over a flat mask of windows of `width` slots whose
+    window g holds n[g] leading set slots, without materializing it: the
+    same (idx, sel, total), from a prefix sum over the windows."""
+    cum = jnp.cumsum(n)
+    total = cum[-1]
+    j = jnp.arange(cap, dtype=cum.dtype)
+    g = jnp.clip(jnp.searchsorted(cum, j, side="right"), 0, n.shape[0] - 1)
+    sel = j < total
+    idx = jnp.where(sel, g * width + j - (cum[g] - n[g]),
+                    n.shape[0] * width - 1)
+    return idx, sel, total
+
+
+def _select_rows(mask, cap: int):
+    """`select_cap` over mask.T.reshape(-1) — a (W, R) mask read row by
+    row — without the transpose, whose (R, W) layout pads W to a full lane
+    tile: a prefix sum over the rows picks each slot's row, one down the
+    row's W slots picks its position. Same (idx, sel, total)."""
+    W, R = mask.shape
+    wcum = jnp.cumsum(mask.astype(jnp.int32), axis=0)     # (W, R)
+    n = wcum[-1]
+    cum = jnp.cumsum(n)
+    total = cum[-1]
+    j = jnp.arange(min(cap, W * R), dtype=cum.dtype)
+    g = jnp.clip(jnp.searchsorted(cum, j, side="right"), 0, R - 1)
+    t = j - (cum[g] - n[g])                    # rank of slot j in row g
+    w = jnp.sum(wcum[:, g] <= t[None, :], axis=0, dtype=cum.dtype)
+    sel = j < total
+    return jnp.where(sel, g * W + w, W * R - 1), sel, total
+
+
 def _materialize_view(triples, perms, hit, pos0, cap: int):
     """Compact matching rows to (min(cap, N), 3), ordered by the pos0 column
     (via the precomputed per-position sort permutations), valid rows first —
@@ -359,9 +391,12 @@ def _join_merge(table, tmask, m_blocks, mm_blocks, pos0, kind, col,
     m_blocks: (S_b, C, 3), mm_blocks: (S_b, C) — one block per gathered
     shard, or a single block for PPN-local steps. verify_mask flags the
     positions some member verifies as a 2nd+ shared column: only those
-    force the (R, S_b*K)-sized candidate gathers before selection — all
-    other candidate values are gathered after, R at a time (XLA:CPU runs
-    large batched gathers on a slow path).
+    force an S_b*K x R candidate mask and its gathers before selection;
+    without them the surviving candidates of each (row, block) window are
+    its first min(count, K) slots, and selection runs over the R x S_b
+    window counts. Candidate values are gathered after selection, R at a
+    time (R and K both grow with the graph: an R x S_b*K intermediate
+    grows with its square).
     """
     R, V = table.shape
     Sb, C = mm_blocks.shape
@@ -375,9 +410,6 @@ def _join_merge(table, tmask, m_blocks, mm_blocks, pos0, kind, col,
     counts = jnp.where(tmask[None, :], hi - lo, 0)       # (S_b, R)
     overflow_fanout = jnp.max(counts) > K
 
-    offs = jnp.arange(K)[None, None, :]
-    pair_ok = ((offs < counts[:, :, None]) & tmask[None, :, None]) \
-        .transpose(1, 0, 2).reshape(R, Sb * K)
     m_flat = m_blocks.reshape(Sb * C, 3)
 
     def cand_idx(order):
@@ -389,29 +421,44 @@ def _join_merge(table, tmask, m_blocks, mm_blocks, pos0, kind, col,
         return blk * C + src
 
     if any(verify_mask):
-        idx_all = cand_idx(jnp.arange(R * Sb * K)).reshape(R, Sb * K)
+        # a 2nd+ shared column can reject any candidate, so the survivors
+        # of a (row, block) window are no longer its prefix: check them
+        # all, laid out (S_b, K, R) so rows run along the minor axis
+        offs = jnp.arange(K)[None, :, None]
+        ok = offs < counts[:, None, :]                  # tmask is in counts
+        src = (jnp.clip(lo[:, None, :] + offs, 0, C - 1)
+               + (jnp.arange(Sb) * C)[:, None, None])   # m_flat rows
         for pos in range(3):
             if not verify_mask[pos]:
                 continue
             chk = is_sh[pos] & (pos != pos0)
             cc = jnp.clip(col[pos], 0, V - 1)
-            pair_ok = pair_ok & jnp.where(
-                chk,
-                m_flat[idx_all, pos] == jnp.take(table, cc, axis=1)[:, None],
+            ok = ok & jnp.where(
+                chk, m_flat[:, pos][src] == jnp.take(table, cc, axis=1),
                 True)
+        def select():                                  # (row, block, k)
+            return _select_rows(ok.reshape(Sb * K, R), R)
+        live = ok.any(axis=(0, 1))
+    else:
+        # the survivors of each (row, block) window are its first
+        # min(count, K) slots: select over the (R, S_b) window sizes, not
+        # over an R x S_b*K pair mask (same pairs, same order)
+        def select():
+            return _select_windows(jnp.minimum(counts, K).T.reshape(-1), K,
+                                   R)
+        live = jnp.any(counts > 0, axis=0)
 
     def expansion():
         # select surviving (row, candidate) pairs first, THEN gather their
         # match values — R gathers instead of R*S_b*K
-        flat = pair_ok.reshape(-1)
-        order, omask, total = _select_cap(flat, R)
+        order, omask, total = select()
         vals = m_flat[cand_idx(order)]               # (R, 3)
         out = _scatter_new(table[order // (Sb * K)],
                            [vals[:, pos] for pos in range(3)], kind, col, V)
         return out, omask, total > R
 
     def semijoin():
-        return table, tmask & pair_ok.any(axis=1), jnp.zeros((), bool)
+        return table, tmask & live, jnp.zeros((), bool)
 
     t2, m2, ovf = _mix(new_mode, kind, expansion, semijoin)
     return t2, m2, ovf | overflow_fanout
@@ -681,8 +728,12 @@ def engine_cost(fn, *args) -> dict:
     normalized ``cost_analysis`` dict — keys of interest are ``"flops"``
     and ``"bytes accessed"``. Feeds the telemetry ``engine_flops`` /
     ``engine_bytes`` gauges (see docs/observability.md)."""
-    from repro.launch.dryrun import cost_dict
     return cost_dict(fn.lower(*args).compile())
+
+
+def cost_dict(compiled) -> dict:
+    """A compiled program's XLA ``cost_analysis`` properties dict."""
+    return compiled.cost_analysis()
 
 
 # ---------------------------------------------------------------------------

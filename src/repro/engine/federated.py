@@ -254,9 +254,10 @@ def run_sharded(plan: PhysicalPlan, kg: ShardedKG, mesh,
 
 def _extract(plan: PhysicalPlan, table, tmask, overflow):
     """Pull the PPN shard's solutions, dedup, sort (matching the oracle)."""
-    t = np.asarray(table[plan.ppn])
-    m = np.asarray(tmask[plan.ppn])
-    ov = bool(np.asarray(overflow[plan.ppn]))
+    # to host first: a mesh-sharded result is not indexable on the device
+    t = np.asarray(table)[plan.ppn]
+    m = np.asarray(tmask)[plan.ppn]
+    ov = bool(np.asarray(overflow)[plan.ppn])
     rows = t[m][:, :plan.n_vars]   # drop the dummy column of 0-var queries
     rows = np.unique(rows, axis=0) if rows.shape[0] \
         else rows.reshape(0, plan.n_vars)

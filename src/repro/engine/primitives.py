@@ -83,24 +83,35 @@ def eq_gates(eqs: tuple[tuple[int, int], ...]) -> np.ndarray:
     return g
 
 
+def pattern_hit(cols, valid, spo, eq=None):
+    """The triple-pattern predicate over the (s, p, o) columns `cols`.
+
+    spo[j] is the constant of position j (-1 = wildcard, -2 =
+    never-match) and eq[k] the gate of EQ_PAIRS[k] (or eq=None); every
+    operand broadcasts against the columns, so the same code runs on
+    (N,) columns with scalar constants here and on (rows, 128) tiles with
+    lane-broadcast constants inside the Pallas kg_scan kernel.
+    """
+    hit = valid
+    for c, v in zip(cols, spo):
+        hit = hit & ((v == -1) | (c == v))
+    hit = hit & (spo[0] != -2) & (spo[1] != -2) & (spo[2] != -2)
+    if eq is not None:
+        for k, (a, b) in enumerate(EQ_PAIRS):
+            hit = hit & (~eq[k] | (cols[a] == cols[b]))
+    return hit
+
+
 def scan_predicate(triples, valid, spo, eq=None):
     """Fused triple-pattern hit mask over one shard block.
 
     triples: (N, 3) int32, valid: (N,) bool; spo: (3,) int32 with -1 =
     wildcard, -2 = never-match; eq: (3,) bool gates over EQ_PAIRS or None.
-    This is the predicate both backends evaluate — the Pallas kg_scan
-    kernel inlines exactly this formulation per block.
+    Both backends evaluate this predicate (`pattern_hit`).
     """
-    s, p, o = spo[0], spo[1], spo[2]
-    hit = valid
-    hit = hit & jnp.where(s == -1, True, triples[:, 0] == s)
-    hit = hit & jnp.where(p == -1, True, triples[:, 1] == p)
-    hit = hit & jnp.where(o == -1, True, triples[:, 2] == o)
-    hit = hit & (s != -2) & (p != -2) & (o != -2)
-    if eq is not None:
-        for k, (a, b) in enumerate(EQ_PAIRS):
-            hit = hit & (~eq[k] | (triples[:, a] == triples[:, b]))
-    return hit
+    return pattern_hit([triples[:, j] for j in range(3)], valid,
+                       [spo[j] for j in range(3)],
+                       None if eq is None else [eq[k] for k in range(3)])
 
 
 def scan_hits(triples, valid, spo, eq=None, *, backend: str = "jnp",

@@ -23,3 +23,8 @@ def default_interpret() -> bool:
     """Pallas kernels execute natively on TPU; everywhere else we run the
     kernel body in interpret mode (Python on CPU) for correctness."""
     return jax.default_backend() != "tpu"
+
+
+def round_up(n: int, m: int) -> int:
+    """n rounded up to a multiple of m (padding to whole kernel tiles)."""
+    return -(-n // m) * m

@@ -33,7 +33,8 @@ def _cost_of(fn, args, in_sh, mesh, out_sh=None):
         kw = {} if out_sh is None else {"out_shardings": out_sh}
         compiled = jax.jit(fn, in_shardings=in_sh,
                            **kw).lower(*args).compile()
-    from repro.launch.dryrun import collective_bytes, cost_dict
+    from repro.engine.batch import cost_dict
+    from repro.launch.dryrun import collective_bytes
     cost = cost_dict(compiled)
     coll = collective_bytes(compiled.as_text())
     return {"flops": float(cost.get("flops", 0.0)),

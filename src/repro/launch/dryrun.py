@@ -25,6 +25,8 @@ import re
 import time
 import traceback
 
+from repro.engine.batch import cost_dict
+
 
 _COLL_RE = re.compile(
     r"=\s*\(?([a-z0-9]+)\[([0-9,]*)\][^=]*?"
@@ -34,15 +36,6 @@ _COLL_RE = re.compile(
 _DT_BYTES = {"f64": 8, "f32": 4, "bf16": 2, "f16": 2, "s64": 8, "u64": 8,
              "s32": 4, "u32": 4, "s16": 2, "u16": 2, "s8": 1, "u8": 1,
              "pred": 1}
-
-
-def cost_dict(compiled) -> dict:
-    """compiled.cost_analysis() across jax versions: 0.4.x returns a list
-    with one properties-dict per program, newer jax returns the dict."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        return cost[0] if cost else {}
-    return cost
 
 
 def collective_bytes(hlo_text: str) -> dict:

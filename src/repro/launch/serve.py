@@ -30,10 +30,12 @@ from __future__ import annotations
 
 import argparse
 import enum
+import os
 import time
 from collections import OrderedDict, deque
 from contextlib import contextmanager, nullcontext
 from dataclasses import dataclass, field
+from pathlib import Path
 from typing import Callable, NamedTuple
 
 import numpy as np
@@ -1454,6 +1456,20 @@ class WorkloadServer:
         self._latencies.clear()
 
 
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR`` wins when set; otherwise the cache lives
+    in ``.jax_cache`` at the root of this checkout — a fixed path, since
+    the path is part of every entry's key and a moving directory never
+    hits."""
+    import jax
+    path = (os.environ.get("JAX_COMPILATION_CACHE_DIR")
+            or str(Path(__file__).resolve().parents[3] / ".jax_cache"))
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
 def build_dataset(dataset: str, scale: float, seed: int = 0):
     """(store, template queries) for "lubm" or "bsbm" at `scale`."""
     if dataset == "lubm":
@@ -1636,6 +1652,7 @@ def main() -> None:
     args = ap.parse_args()
     if args.batch < 1:
         ap.error("--batch must be >= 1")
+    use_compile_cache()
 
     mesh = None
     if args.sharded:
@@ -1705,6 +1722,7 @@ def main() -> None:
     # warmup, the serving loop, and its report run under one try so an
     # interrupt anywhere (compiles included) still drains gracefully and
     # still emits the --trace-out/--metrics-out artifacts (the finally)
+    # before the run exits non-zero
     try:
         # warm every (bucket, padded batch size) shape the stream will
         # produce — serving throughput below is steady-state, compile-free
@@ -1744,15 +1762,18 @@ def main() -> None:
                 n_solutions = sum(t.result[1] for t in answered)
                 overflows = sum(bool(t.result[2]) for t in answered)
                 served = len(tickets)
+                shed = served - len(answered)
             else:
                 t0 = clock()
                 served = 0
+                shed = 0
                 n_solutions = 0
                 overflows = 0
                 while served < len(stream):
                     chunk = stream[served:served + args.batch]
                     for res in server.serve(chunk):
                         if res is None:     # shed with a typed error
+                            shed += 1
                             continue
                         n_solutions += res[1]
                         overflows += bool(res[2])
@@ -1801,13 +1822,16 @@ def main() -> None:
                       + (f", rewrote {mig['plans_rewritten']} plans, "
                          f"reused {mig['signatures_reused']} engine sigs"
                          if mig else ""))
-    except (KeyboardInterrupt, SystemExit):
+        if shed and fault_plan is None:
+            raise SystemExit(f"{shed} requests shed without --chaos")
+    except KeyboardInterrupt:
         out = server.shutdown(args.grace_ms / 1e3)
         st = server.stats
         print(f"\ninterrupted: drained {out['drained']} and shed "
               f"{out['shed']} queued requests within the "
               f"{args.grace_ms:g} ms grace budget; "
               f"served={st['served']} total")
+        raise
     finally:
         if args.trace_out:
             telemetry.dump_trace(args.trace_out)

@@ -122,16 +122,11 @@ def recsys_rules(mesh, **_kw) -> list[Rule]:
 
 def shard_map_compat(kernel, *, mesh, in_specs, out_specs,
                      check_rep: bool = True):
-    """shard_map across jax versions: `jax.shard_map(check_vma=...)` arrived
-    after 0.4.x; older builds only have the experimental module with its
-    `check_rep` spelling. The single place the repo spells this out — the
-    KG engines and the transformer perf paths all route through here."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(kernel, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_rep)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(kernel, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=check_rep)
+    """`jax.shard_map` with the replication check as a flag — the single
+    place the repo spells it out; the KG engines and the transformer perf
+    paths all route through here."""
+    return jax.shard_map(kernel, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_rep)
 
 
 def kg_specs(axis: str = "shards") -> tuple[P, P, P, P, P]:

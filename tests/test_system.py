@@ -2,7 +2,10 @@
 workload, rewrite queries, execute federated — answers identical to a
 centralized store, with strictly less cross-shard communication than the
 random baseline (the paper's Fig. 5-8 claim at the semantics level)."""
+from pathlib import Path
+
 import numpy as np
+import pytest
 
 from repro.core.partitioner import (centralized_partition, random_partition,
                                     wawpart_partition)
@@ -68,3 +71,24 @@ def test_balance_matches_paper_band(lubm_small):
     part = wawpart_partition(lubm_small, lubm_queries(), n_shards=3)
     dev = part.balance_report()["rel_dev"]
     assert min(dev) >= -0.16 and max(dev) <= 0.16
+
+
+@pytest.mark.parametrize("env_dir", ["outside", None])
+def test_compile_cache_dir(env_dir, tmp_path, monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR wins when set; otherwise the cache sits at
+    a fixed directory inside the checkout."""
+    import jax
+
+    from repro.launch.serve import use_compile_cache
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        want = str(Path(__file__).resolve().parents[1] / ".jax_cache")
+    else:
+        want = str(tmp_path / env_dir)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", want)
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        assert use_compile_cache() == want
+        assert jax.config.jax_compilation_cache_dir == want
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
