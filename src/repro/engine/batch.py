@@ -468,6 +468,21 @@ def _join_merge(table, tmask, m_blocks, mm_blocks, pos0, kind, col,
 # bucket engine
 # ---------------------------------------------------------------------------
 
+def program_name(sig: BucketSignature, backend: str = "jnp") -> str:
+    """The bucket program's name, from its signature: ``kg_L<steps>_V<table
+    width>_R<table cap>``, plus ``_<backend>`` off the jnp backend. Its jit
+    module is ``jit_<name>``, the same on every run, so a profile says
+    which bucket a device op belongs to."""
+    name = f"kg_L{sig.n_steps}_V{sig.n_vars}_R{sig.table_cap}"
+    return name if backend == "jnp" else f"{name}_{backend}"
+
+
+def _named(fn, name: str):
+    """`fn` renamed, so that jit names its module after `name`."""
+    fn.__name__ = fn.__qualname__ = name
+    return fn
+
+
 def make_batched_engine(sig: BucketSignature, *, join_impl: str = "expand",
                         max_per_row: int | None = None,
                         gather_cap: int | None = None,
@@ -510,61 +525,66 @@ def make_batched_engine(sig: BucketSignature, *, join_impl: str = "expand",
 
         for i in range(L):
             cap = sig.scan_caps[i]
-            spo = pd.consts[i]
-            if sig.param_bits[i]:
-                spo = jnp.where(pd.pidx[i] >= 0,
-                                params[jnp.clip(pd.pidx[i], 0)], spo)
-            eq = pd.eq[i] if sig.eq_bits[i] else None
-            va = valid
-            if sig.gather_bits[i] and S > 1:
-                # owner gate folded into the validity mask so the fused
-                # scan's hit-count already reflects it (== hit & owner)
-                va = va & pd.owner[i, my]
             merge = (i > 0 and join_impl == "sorted" and sig.sorted_bits[i])
-
-            if merge:   # matches per block, pos0-key-sorted by construction
-                pos0 = jnp.argmax(pd.kind[i] == 1)
-                if backend == "pallas":
-                    # scan the permuted view directly: the kernel's fused
-                    # hit-count is then the compaction cumsum for the
-                    # sorted-by-construction block (rowwise predicate
-                    # commutes with the permutation)
-                    perm = perms[pos0]
-                    tp = triples[perm]
-                    _, cum = scan_hits(tp, va[perm], spo, eq,
-                                       backend=backend, blocks=blocks)
-                    idx, mm, total = select_from_cum(cum, min(cap, N))
-                    m = tp[idx]
-                    step_ovf = (total > cap) if cap < N \
-                        else jnp.zeros((), bool)
+            gather = sig.gather_bits[i] and S > 1
+            with jax.named_scope(f"step{i}/scan"):
+                spo = pd.consts[i]
+                if sig.param_bits[i]:
+                    spo = jnp.where(pd.pidx[i] >= 0,
+                                    params[jnp.clip(pd.pidx[i], 0)], spo)
+                eq = pd.eq[i] if sig.eq_bits[i] else None
+                va = valid
+                if gather:
+                    # owner gate folded into the validity mask so the fused
+                    # scan's hit-count already reflects it (== hit & owner)
+                    va = va & pd.owner[i, my]
+                if merge:   # matches per block, pos0-sorted by construction
+                    pos0 = jnp.argmax(pd.kind[i] == 1)
+                    if backend == "pallas":
+                        # scan the permuted view directly: the kernel's
+                        # fused hit-count is then the compaction cumsum for
+                        # the sorted-by-construction block (rowwise
+                        # predicate commutes with the permutation)
+                        perm = perms[pos0]
+                        tp = triples[perm]
+                        _, cum = scan_hits(tp, va[perm], spo, eq,
+                                           backend=backend, blocks=blocks)
+                        idx, mm, total = select_from_cum(cum, min(cap, N))
+                        m = tp[idx]
+                        step_ovf = (total > cap) if cap < N \
+                            else jnp.zeros((), bool)
+                    else:
+                        hit, _ = scan_hits(triples, va, spo, eq)
+                        m, mm, step_ovf = _materialize_view(
+                            triples, perms, hit, pos0, cap)
                 else:
-                    hit, _ = scan_hits(triples, va, spo, eq)
-                    m, mm, step_ovf = _materialize_view(triples, perms, hit,
-                                                        pos0, cap)
-                if sig.gather_bits[i] and S > 1:
-                    m = jax.lax.all_gather(m, axis_name)       # (S, C, 3)
-                    mm = jax.lax.all_gather(mm, axis_name)     # (S, C)
-                else:
-                    m, mm = m[None], mm[None]
-                K = sig.fanout_caps[i] if max_per_row is None \
-                    else min(max_per_row, sig.fanout_caps[i])
-                t2, m2, ovf_j = _join_merge(
-                    table, tmask, m, mm, pos0, pd.kind[i], pd.col[i],
-                    sig.new_modes[i], max_per_row=K,
-                    verify_mask=sig.verify_masks[i], backend=backend,
-                    blocks=blocks)
-            else:
-                hit, cum = scan_hits(triples, va, spo, eq, backend=backend,
-                                     blocks=blocks)
-                m, mm, step_ovf = _materialize(triples, hit, cum, cap)
-                if sig.gather_bits[i] and S > 1:
+                    hit, cum = scan_hits(triples, va, spo, eq,
+                                         backend=backend, blocks=blocks)
+                    m, mm, step_ovf = _materialize(triples, hit, cum, cap)
+            with jax.named_scope(f"step{i}/gather"):
+                if merge:
+                    if gather:
+                        m = jax.lax.all_gather(m, axis_name)    # (S, C, 3)
+                        mm = jax.lax.all_gather(mm, axis_name)  # (S, C)
+                    else:
+                        m, mm = m[None], mm[None]
+                elif gather:
                     C = m.shape[0]
                     m = jax.lax.all_gather(m, axis_name).reshape(S * C, 3)
                     mm = jax.lax.all_gather(mm, axis_name).reshape(S * C)
                     if gather_cap is not None and gather_cap < S * C:
                         m, mm, ovf_g = compact(m, mm, gather_cap)
                         step_ovf = step_ovf | ovf_g
-                if i == 0:
+            with jax.named_scope(f"step{i}/join"):
+                if merge:
+                    K = sig.fanout_caps[i] if max_per_row is None \
+                        else min(max_per_row, sig.fanout_caps[i])
+                    t2, m2, ovf_j = _join_merge(
+                        table, tmask, m, mm, pos0, pd.kind[i], pd.col[i],
+                        sig.new_modes[i], max_per_row=K,
+                        verify_mask=sig.verify_masks[i], backend=backend,
+                        blocks=blocks)
+                elif i == 0:
                     t2, m2, ovf_j = _seed_join(table, m, mm, pd.kind[i],
                                                pd.col[i], sig.new_modes[i])
                 else:
@@ -573,17 +593,17 @@ def make_batched_engine(sig: BucketSignature, *, join_impl: str = "expand",
                                                sig.new_modes[i],
                                                backend=backend,
                                                blocks=blocks)
-            if sig.noop_bits[i]:         # some member pads here: gate
-                noop = pd.noop[i]
-                table = jnp.where(noop, table, t2)
-                tmask = jnp.where(noop, tmask, m2)
-                overflow = overflow | (~noop & (step_ovf | ovf_j))
-            else:
-                table, tmask = t2, m2
-                overflow = overflow | step_ovf | ovf_j
+                if sig.noop_bits[i]:         # some member pads here: gate
+                    noop = pd.noop[i]
+                    table = jnp.where(noop, table, t2)
+                    tmask = jnp.where(noop, tmask, m2)
+                    overflow = overflow | (~noop & (step_ovf | ovf_j))
+                else:
+                    table, tmask = t2, m2
+                    overflow = overflow | step_ovf | ovf_j
         return table, tmask, overflow
 
-    return engine
+    return _named(engine, program_name(sig, backend))
 
 
 def make_sharded_batched_engine(sig: BucketSignature, mesh, *,
@@ -633,7 +653,7 @@ def make_sharded_batched_engine(sig: BucketSignature, mesh, *,
         return (jnp.swapaxes(t, 0, 1), jnp.swapaxes(m, 0, 1),
                 jnp.swapaxes(o, 0, 1))
 
-    return jax.jit(fn)
+    return jax.jit(_named(fn, engine.__name__))
 
 
 class EngineCache:
@@ -718,17 +738,6 @@ class EngineCache:
         """Always truthy: an empty cache is still a cache (``__len__``
         would otherwise make `cache or EngineCache()` drop a fresh one)."""
         return True
-
-
-def engine_cost(fn, *args) -> dict:
-    """XLA cost-analysis properties for a jitted engine on concrete args.
-
-    Lowers and compiles ``fn`` for the given argument shapes (a cache hit
-    inside XLA when the engine already ran on them) and returns the
-    normalized ``cost_analysis`` dict — keys of interest are ``"flops"``
-    and ``"bytes accessed"``. Feeds the telemetry ``engine_flops`` /
-    ``engine_bytes`` gauges (see docs/observability.md)."""
-    return cost_dict(fn.lower(*args).compile())
 
 
 def cost_dict(compiled) -> dict:
@@ -822,14 +831,21 @@ def assemble_batch(bucket: PlanBucket,
     return stacked, jnp.asarray(pvecs)
 
 
+def fetch_outputs(table, tmask, overflow
+                  ) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """An engine call's (table, mask, overflow) as host arrays: the
+    device-to-host copy of the whole padded batch, every shard (a no-op
+    on arrays already on the host)."""
+    return np.asarray(table), np.asarray(tmask), np.asarray(overflow)
+
+
 def extract_batch(bucket: PlanBucket,
                   requests: list[tuple[int, np.ndarray | None]],
                   table, tmask, overflow):
     """Per-request (solutions, count, overflow), PPN shard, sorted + deduped
-    (mirrors federated._extract so results compare bit-identically)."""
-    table = np.asarray(table)
-    tmask = np.asarray(tmask)
-    overflow = np.asarray(overflow)
+    (mirrors federated._extract so results compare bit-identically).
+
+    Takes host arrays, as `fetch_outputs` returns them."""
     out = []
     for r, (idx, _) in enumerate(requests):
         plan = bucket.plans[idx]
@@ -915,9 +931,9 @@ def run_batched(bucket: PlanBucket, kg: ShardedKG,
     pd, params = assemble_batch(bucket, exec_reqs)
     if perms is None:
         perms = shard_perms(kg)
-    table, tmask, overflow = fn(jnp.asarray(kg.triples),
-                                jnp.asarray(kg.valid),
-                                jnp.asarray(perms), pd, params)
+    table, tmask, overflow = fetch_outputs(*fn(jnp.asarray(kg.triples),
+                                               jnp.asarray(kg.valid),
+                                               jnp.asarray(perms), pd, params))
     if inverse is None:
         out = extract_batch(bucket, exec_reqs, table, tmask, overflow)
     else:

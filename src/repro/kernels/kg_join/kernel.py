@@ -106,6 +106,7 @@ def join_ranges_kernel(keys: jax.Array, rkey: jax.Array, *, n_blocks: int,
         scratch_shapes=[pltpu.VMEM((s8, block_rows), jnp.int32),
                         pltpu.VMEM((s8, block_rows), jnp.int32)],
         interpret=interpret,
+        name="kg_join_ranges",
     )(keys, rkey)
 
 
@@ -151,4 +152,5 @@ def compat_matrix_kernel(join: jax.Array, table: jax.Array,
                                lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((r, c), jnp.int8),
         interpret=interpret,
+        name="kg_join_compat",
     )(join, table, matches)

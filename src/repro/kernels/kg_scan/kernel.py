@@ -89,4 +89,5 @@ def scan_hits_kernel(pattern: jax.Array, cols: jax.Array, *,
         out_specs=[tile, tile],
         out_shape=[jax.ShapeDtypeStruct((rows, LANES), jnp.int32)] * 2,
         interpret=interpret,
+        name="kg_scan",
     )(pattern, cols)

@@ -47,7 +47,7 @@ from repro.core.partitioner import (Partitioning, centralized_partition,
                                     random_partition, wawpart_partition)
 from repro.engine.batch import (EngineCache, bucket_collectives, bucket_plans,
                                 canonical_params, dedup_requests,
-                                extract_batch, extract_fanout,
+                                extract_batch, extract_fanout, fetch_outputs,
                                 pad_requests_pow2, shard_perms, stage_batch)
 from repro.engine.federated import ShardedKG
 from repro.engine.planner import make_plan
@@ -86,6 +86,10 @@ class Counter(str, enum.Enum):
     SHARD_DOWN = "shard_down"          # degraded-mode activations
     MIGRATION_ABORTS = "migration_aborts"  # migrate() prepares rolled back
     ENGINE_CACHE_EVICTIONS = "engine_cache_evictions"  # LRU engine evictions
+    D2H_BYTES = "d2h_bytes"            # engine output bytes copied to host
+    TABLE_ROWS_LIVE = "table_rows_live"  # mask-true rows of executed rows
+    TABLE_ROWS_CAP = "table_rows_cap"  # executed rows x shards x table cap
+    BATCH_ROWS_PADDED = "batch_rows_padded"  # power-of-two filler rows
 
 
 @dataclass(frozen=True)
@@ -462,32 +466,6 @@ class WorkloadServer:
                        shard=str(s))
         tele.gauge("shard_load_imbalance", snap.imbalance(n_shards))
 
-    def record_engine_costs(self) -> dict[str, list[float]]:
-        """Publish XLA ``cost_analysis`` FLOPs/bytes per bucket engine.
-
-        Lowers each bucket's engine on a minimal (padded batch 1) staged
-        request and sets the `engine_flops`/`engine_bytes` gauges.
-        Returns {"flops": [...], "bytes": [...]} in bucket order. Costs
-        are per-dispatch at that minimal batch shape — a relative
-        weight across buckets, not a throughput prediction.
-        """
-        from repro.engine.batch import engine_cost
-        st = self._state
-        flops: list[float] = []
-        nbytes: list[float] = []
-        for bi, bucket in enumerate(st.buckets):
-            fn = self._engine(bucket)
-            pd, params = stage_batch(bucket, pad_requests_pow2([(0, None)]),
-                                     mesh=self.mesh)
-            cost = engine_cost(fn, st.tr, st.va, st.perms, pd, params)
-            f = float(cost.get("flops", 0.0) or 0.0)
-            b = float(cost.get("bytes accessed", 0.0) or 0.0)
-            self.telemetry.gauge("engine_flops", f, bucket=str(bi))
-            self.telemetry.gauge("engine_bytes", b, bucket=str(bi))
-            flops.append(f)
-            nbytes.append(b)
-        return {"flops": flops, "bytes": nbytes}
-
     # ---- migration -----------------------------------------------------
 
     def _query_units(self, q, part: Partitioning) -> set:
@@ -843,9 +821,21 @@ class WorkloadServer:
         now = self.pipeline.clock()
         self._poll_faults(now)
         self._sync_queues()
+        bi, pi = self._state.route[name]
+        # the span covers the server's own work for the ticket (tracker,
+        # cache lookup, enqueue) and ends before the nested pump()
+        with self.telemetry.span("submit", f"bucket{bi}"):
+            ticket = self._admit(name, params, deadline_ms, now, bi, pi)
+        if _pump and not ticket.done:
+            self.pump()
+        return ticket
+
+    def _admit(self, name: str, params, deadline_ms, now: float, bi: int,
+               pi: int) -> Ticket:
+        """`submit`'s work after routing: feed the tracker, then answer
+        from the answer cache, shed, or enqueue into bucket bi."""
         st = self._state
         tele = self.telemetry
-        bi, pi = st.route[name]
         plan = st.buckets[bi].plans[pi]
         # cache hits still feed the tracker: drift detection must see
         # the real mix even at high hit rates
@@ -910,8 +900,6 @@ class WorkloadServer:
 
         self._queues.setdefault(bi, []).append(ticket)
         tele.gauge("queue_depth", len(self._queues[bi]), bucket=str(bi))
-        if _pump:
-            self.pump()
         return ticket
 
     def pump(self) -> int:
@@ -992,7 +980,7 @@ class WorkloadServer:
                 raise RuntimeError("drain() made no progress after "
                                    "100000 flush rounds")
         while self._inflight:
-            self._complete(self._inflight.popleft())
+            self._complete(self._inflight.popleft(), "drain")
         self.telemetry.gauge("inflight", 0)
         self._refresh_shard_load()
         self.telemetry.check_invariants()
@@ -1035,7 +1023,7 @@ class WorkloadServer:
                 shed_n += 1
             self.telemetry.gauge("queue_depth", 0, bucket=str(bi))
         while self._inflight:
-            self._complete(self._inflight.popleft())
+            self._complete(self._inflight.popleft(), "drain")
         self.telemetry.gauge("inflight", 0)
         self._refresh_shard_load()
         self.telemetry.check_invariants()
@@ -1155,7 +1143,6 @@ class WorkloadServer:
 
         st = self._state
         tele = self.telemetry
-        tr = tele.trace
         bucket = st.buckets[bi]
         b_lab = str(bi)
         tele.gauge("queue_depth", len(rest), bucket=b_lab)
@@ -1172,34 +1159,23 @@ class WorkloadServer:
             unique, inverse = reqs, None
         tele.observe("dedup_fanout", len(take) / len(unique), bucket=b_lab)
         fn = self._engine(bucket)
-        t_stage = tr.clock() if tr.enabled else now
-        if self.faults is None and self.retry is None:
-            # fault-free fast path: byte-for-byte the pre-fault dispatch
-            pd, params = stage_batch(bucket, pad_requests_pow2(unique),
-                                     mesh=self.mesh)
-            t_call = tr.clock() if tr.enabled else now
-            with tele.annotation(f"dispatch/bucket{bi}"):
-                out = fn(st.tr, st.va, st.perms, pd, params)
-        else:
-            try:
-                if self.faults is not None:
-                    self.faults.on_dispatch(bi)
-                pd, params = stage_batch(bucket, pad_requests_pow2(unique),
-                                         mesh=self.mesh)
-                t_call = tr.clock() if tr.enabled else now
-                with tele.annotation(f"dispatch/bucket{bi}"):
-                    out = fn(st.tr, st.va, st.perms, pd, params)
-            except Exception as exc:
-                self._flush_failed(bi, take, exc, now)
-                return
-        t_dispatch = self.pipeline.clock()
-        if tr.enabled:
-            lane = f"bucket{bi}"
-            tr.complete(f"flush/{reason}", now, t_dispatch, tid=lane,
-                        args={"n": len(take), "unique": len(unique),
-                              "epoch": st.epoch})
-            tr.complete("stage", t_stage, t_call, tid=lane)
-            tr.complete("dispatch", t_call, t_dispatch, tid=lane)
+        lane = f"bucket{bi}"
+        with tele.span(f"flush/{reason}", lane, n=len(take),
+                       unique=len(unique), epoch=st.epoch):
+            if self.faults is None and self.retry is None:
+                # fault-free fast path: byte-for-byte the pre-fault dispatch
+                out, n_pad = self._stage_and_call(fn, bucket, unique, lane)
+            else:
+                try:
+                    if self.faults is not None:
+                        self.faults.on_dispatch(bi)
+                    out, n_pad = self._stage_and_call(fn, bucket, unique,
+                                                      lane)
+                except Exception as exc:
+                    self._flush_failed(bi, take, exc, now)
+                    return
+            t_dispatch = self.pipeline.clock()
+        tele.count("batch_rows_padded", n_pad, bucket=b_lab)
         for t in take:
             t.t_dispatch = t_dispatch
             t.epoch = st.epoch
@@ -1207,8 +1183,20 @@ class WorkloadServer:
                                         out, st.epoch,
                                         self._degraded is not None))
         while len(self._inflight) > self.pipeline.max_inflight:
-            self._complete(self._inflight.popleft())
+            self._complete(self._inflight.popleft(), "backpressure")
         tele.gauge("inflight", len(self._inflight))
+
+    def _stage_and_call(self, fn, bucket, unique: list, lane: str):
+        """Stage the power-of-two padded batch and issue the engine call
+        (asynchronous); returns (engine output, filler rows added)."""
+        st = self._state
+        tele = self.telemetry
+        with tele.span("stage", lane):
+            padded = pad_requests_pow2(unique)
+            pd, params = stage_batch(bucket, padded, mesh=self.mesh)
+        with tele.span("dispatch", lane):
+            out = fn(st.tr, st.va, st.perms, pd, params)
+        return out, len(padded) - len(unique)
 
     def _in_backoff(self, bi: int, now: float) -> bool:
         """Whether bucket bi sits inside a retry backoff window."""
@@ -1318,30 +1306,58 @@ class WorkloadServer:
         while self._inflight and all(
                 getattr(a, "is_ready", lambda: True)()
                 for a in self._inflight[0].out):
-            done += self._complete(self._inflight.popleft())
+            done += self._complete(self._inflight.popleft(), "ready")
         return done
 
-    def _complete(self, rec: _Inflight) -> int:
+    def _complete(self, rec: _Inflight, why: str) -> int:
         """Extract one in-flight batch and deliver its results.
 
-        Blocks until the device output is ready, runs the host-side
-        extraction (per-unique np.unique, fan-out to duplicates), stamps
-        done-times, fills the answer cache (only when the serving epoch
-        still matches the dispatch epoch — a migration mid-flight makes
-        the answers stale before they ever land), and bumps the
-        served/executed/deduped counters. Returns the delivered count.
+        Blocks until the device output is ready, copies it to the host,
+        runs the host-side extraction (per-unique np.unique, fan-out to
+        duplicates), stamps done-times, fills the answer cache (only when
+        the serving epoch still matches the dispatch epoch — a migration
+        mid-flight makes the answers stale before they ever land), and
+        bumps the served/executed/deduped counters and the extraction
+        and table-fill counters. `why` says what made the caller wait:
+        "ready" (`_retire` found it done), "backpressure" (`max_inflight`
+        exceeded in `_flush`) or "drain". Returns the delivered count.
         """
         import jax
 
         tele = self.telemetry
+        lane = f"bucket{rec.bi}"
+        b_lab = str(rec.bi)
+        n_exec = len(rec.unique)
+        with tele.span("retire", lane, n=len(rec.tickets), epoch=rec.epoch):
+            with tele.span("wait", lane, why=why):
+                jax.block_until_ready(rec.out)
+            nbytes = sum(int(a.nbytes) for a in rec.out)
+            with tele.span("fetch", lane, bytes=nbytes):
+                table, tmask, overflow = fetch_outputs(*rec.out)
+            with tele.span("extract", lane, n=n_exec):
+                if rec.inverse is None:
+                    extracted = extract_batch(rec.bucket, rec.unique, table,
+                                              tmask, overflow)
+                else:
+                    extracted = extract_fanout(rec.bucket, rec.unique,
+                                               rec.inverse, table, tmask,
+                                               overflow)
+            # the (batch, shard, table cap) mask of the executed rows: its
+            # live rows against the rows the engine carried for them
+            executed = tmask[:n_exec]
+            tele.count("d2h_bytes", nbytes, bucket=b_lab)
+            tele.count("table_rows_live", int(np.count_nonzero(executed)),
+                       bucket=b_lab)
+            tele.count("table_rows_cap", executed.size, bucket=b_lab)
+            with tele.span("deliver", lane, n=len(rec.tickets)):
+                self._deliver(rec, extracted)
+        return len(rec.tickets)
+
+    def _deliver(self, rec: _Inflight, extracted: list) -> None:
+        """Hand each ticket of `rec` its extracted answer: done stamps,
+        counters, latency histogram, ticket spans and answer-cache fill."""
+        tele = self.telemetry
         tr = tele.trace
-        t_retire = tr.clock() if tr.enabled else None
-        jax.block_until_ready(rec.out)
-        if rec.inverse is None:
-            extracted = extract_batch(rec.bucket, rec.unique, *rec.out)
-        else:
-            extracted = extract_fanout(rec.bucket, rec.unique, rec.inverse,
-                                       *rec.out)
         now = self.pipeline.clock()
         fill = (self.answer_cache_cap > 0 and not self._cache_bypass
                 and rec.epoch == self._state.epoch)
@@ -1350,9 +1366,6 @@ class WorkloadServer:
         if len(rec.tickets) > len(rec.unique):
             tele.count("deduped", len(rec.tickets) - len(rec.unique),
                        bucket=b_lab)
-        if tr.enabled:
-            tr.complete("retire", t_retire, now, tid=f"bucket{rec.bi}",
-                        args={"n": len(rec.tickets), "epoch": rec.epoch})
         for t, res in zip(rec.tickets, extracted):
             t.result = res
             t.t_done = now
@@ -1377,7 +1390,6 @@ class WorkloadServer:
                     self._answers[key] = res
                     if len(self._answers) > self.answer_cache_cap:
                         self._answers.popitem(last=False)
-        return len(rec.tickets)
 
     # ---- serving -------------------------------------------------------
 
@@ -1449,7 +1461,7 @@ class WorkloadServer:
         """Zero every stats counter (and histogram), drop the recorded
         latencies, and clear the trace buffer — the steady-state
         measurement boundary after warmup. State gauges (epoch, cut
-        collectives, engine costs) persist: they describe the current
+        collectives) persist: they describe the current
         serving state, not accumulated traffic."""
         self.telemetry.reset_counters()
         self.telemetry.trace.clear()
@@ -1747,11 +1759,6 @@ def main() -> None:
                   f"{rep['collectives_after']}")
             for i in range(0, len(stream), args.batch):
                 server.warmup(stream[i:i + args.batch])
-
-        if args.metrics_out:
-            # per-bucket cost_analysis gauges ride along in the snapshot;
-            # engines are already compiled (warmup), lowering is cheap
-            server.record_engine_costs()
 
         server.reset_stats()
         with profile_ctx:

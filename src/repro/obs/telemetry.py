@@ -7,9 +7,10 @@ imports it (stdlib-only, no jax) to verify the documented metric table
 in docs/observability.md matches what the code declares.
 
 `Telemetry` bundles the registry with a `TraceRecorder` and the optional
-`jax.profiler` annotation hook, and enforces the counter invariants from
-docs/architecture.md ("Stats counters") via `check_invariants()` — the
-serving pipeline calls it at `drain()`.
+`jax.profiler` annotation hook behind one span call (`Telemetry.span`),
+and enforces the counter invariants from docs/architecture.md ("Stats
+counters") via `check_invariants()` — the serving pipeline calls it at
+`drain()`.
 """
 from __future__ import annotations
 
@@ -60,6 +61,14 @@ SERVING_SCHEMA: tuple[tuple, ...] = (
      "migrate() prepare phases rolled back before the epoch swap."),
     ("engine_cache_evictions", "counter", (),
      "Compiled engines evicted from the LRU-capped EngineCache."),
+    ("d2h_bytes", "counter", ("bucket",),
+     "Bytes of engine output (table, mask, overflow) copied to the host."),
+    ("table_rows_live", "counter", ("bucket",),
+     "Mask-true binding-table rows of executed batch rows, all shards."),
+    ("table_rows_cap", "counter", ("bucket",),
+     "Table rows the engine carried: executed rows x shards x table cap."),
+    ("batch_rows_padded", "counter", ("bucket",),
+     "Filler rows added to pad a dispatched batch to a power of two."),
     ("queue_depth", "gauge", ("bucket",),
      "Tickets currently queued per bucket (set on enqueue/flush)."),
     ("inflight", "gauge", (),
@@ -72,10 +81,6 @@ SERVING_SCHEMA: tuple[tuple, ...] = (
      "Requests in the tracker window touching the shard (live load)."),
     ("shard_load_imbalance", "gauge", (),
      "Max/mean of per-shard request touches over the tracker window."),
-    ("engine_flops", "gauge", ("bucket",),
-     "XLA cost_analysis FLOPs for the bucket's compiled engine."),
-    ("engine_bytes", "gauge", ("bucket",),
-     "XLA cost_analysis bytes accessed for the bucket's engine."),
     ("batch_fill_ratio", "histogram", ("bucket",),
      "Tickets per flush / max_batch.", (0.25, 0.5, 0.75, 1.0)),
     ("dedup_fanout", "histogram", ("bucket",),
@@ -110,9 +115,10 @@ class Telemetry:
 
     Constructed cheaply with everything off by default: `trace=False`
     keeps the recorder disabled (no-op on every path), `annotate=False`
-    keeps `annotation()` a nullcontext, and the metric registry is plain
-    dict arithmetic. The serving pipeline calls `bind_clock()` with its
-    injected clock so trace timestamps share the tickets' timebase.
+    opens no profiler annotation, `span()` with both off is a shared
+    null context, and the metric registry is plain dict arithmetic. The
+    serving pipeline calls `bind_clock()` with its injected clock so
+    trace timestamps share the tickets' timebase.
     """
 
     def __init__(self, *, trace: bool = False, annotate: bool = False,
@@ -176,14 +182,43 @@ class Telemetry:
         """Write the Chrome trace-event JSON to `path`."""
         self.trace.dump(path)
 
-    # -- profiler hook -----------------------------------------------------
+    # -- spans -------------------------------------------------------------
 
-    def annotation(self, name: str):
-        """A `jax.profiler.TraceAnnotation(name)` scope when annotation
-        is on (imported lazily), else a free nullcontext."""
+    def span(self, name: str, lane: str, **args):
+        """Context manager timing its body as one span on both clocks.
+
+        With `trace` on, records the recorder's complete span `name` on
+        track `lane` (pipeline-clock seconds, `args` as its args). With
+        `annotate` on, opens a `jax.profiler.TraceAnnotation` over the
+        same interval, named ``dispatch/<lane>/<name>`` — except the span
+        named ``dispatch`` (the engine call), which keeps the profiler
+        name ``dispatch/<lane>``. With both off it returns a shared null
+        context: no clock read, no event, no annotation.
+        """
+        if not (self.trace.enabled or self.annotate):
+            return _NULL_SPAN
+        return self._span(name, lane, args)
+
+    @contextmanager
+    def _span(self, name: str, lane: str, args: dict):
+        rec = self.trace
+        with self._annotation(name, lane):
+            if not rec.enabled:
+                yield
+                return
+            t0 = rec.clock()
+            try:
+                yield
+            finally:
+                rec.complete(name, t0, rec.clock(), tid=lane, args=args)
+
+    def _annotation(self, name: str, lane: str):
+        """The profiler half of `span`: a lazily imported
+        `TraceAnnotation` when annotation is on, else the null span."""
         if not self.annotate:
-            return nullcontext()
-        return _jax_annotation(name)
+            return _NULL_SPAN
+        return _jax_annotation(f"dispatch/{lane}" if name == "dispatch"
+                               else f"dispatch/{lane}/{name}")
 
     # -- invariants --------------------------------------------------------
 
@@ -216,10 +251,13 @@ class Telemetry:
                 f"({totals['timeouts']} timeouts > {totals['shed']} shed)")
 
 
-@contextmanager
+#: Shared by every span while tracing and annotation are off.
+_NULL_SPAN = nullcontext()
+
+
 def _jax_annotation(name: str):
-    """Lazy `jax.profiler.TraceAnnotation` so this module never imports
-    jax at module scope (the docs gate imports the schema without it)."""
+    """A `jax.profiler.TraceAnnotation`, imported lazily so this module
+    never imports jax at module scope (the docs gate imports the schema
+    without it)."""
     from jax.profiler import TraceAnnotation
-    with TraceAnnotation(name):
-        yield
+    return TraceAnnotation(name)

@@ -205,11 +205,14 @@ def test_backoff_window_skips_pump_flushes(lubm_served):
     server.pump()
     assert not t.done and server.stats["retries"] == 1
     server.pump()                   # still inside the backoff window
-    assert not t.done
+    assert not t.done and t.t_dispatch is None
     ck.advance(0.010)               # backoff (<= 2 ms jittered) elapsed
     server.pump()
-    assert t.done and t.error is None
+    # this pump flushed the retry; its asynchronous dispatch need not be
+    # ready yet, so the answer is delivered by the drain
+    assert t.t_dispatch == ck() and server.stats["retries"] == 1
     server.drain()
+    assert t.done and t.error is None
 
 
 def test_fault_free_parity_with_empty_injector(lubm_served):

@@ -13,10 +13,16 @@ The invariants this file owns:
   * the drain-time self-check fires on a deliberately broken counter;
   * tracing disabled records zero events and stays bit-identical to the
     traced path;
-  * cut_collectives gauges equal WorkloadServer.collective_counts() and
-    record_engine_costs publishes per-bucket FLOPs/bytes.
+  * cut_collectives gauges equal WorkloadServer.collective_counts();
+  * `Telemetry.span` records the recorder's span and opens one profiler
+    annotation, and with both off reads no clock; a traced request shows
+    its server phases (submit, flush, stage, dispatch, retire and the
+    wait/fetch/extract/deliver inside it) on its bucket lane;
+  * the extraction and table-fill counters count a hand-built batch, and
+    bucket programs are named by their signature.
 """
 import json
+from contextlib import contextmanager
 
 import numpy as np
 import pytest
@@ -339,18 +345,268 @@ def test_tracing_disabled_zero_events_bit_identical(lubm_served):
     assert len(plain.telemetry.trace) == 0
     for a, b in zip(want, got):
         assert _eq(a, b)
+    # the counters, extraction and table fill included, do not depend on
+    # tracing
+    assert plain.stats == traced.stats
 
 
-def test_record_engine_costs_publishes_gauges(lubm_served):
+# ---------------------------------------------------------------------------
+# spans on both clocks
+# ---------------------------------------------------------------------------
+
+class Annotations:
+    """Stands in for `jax.profiler.TraceAnnotation`: records each name
+    opened and closed."""
+
+    def __init__(self):
+        self.opened, self.closed = [], []
+
+    def __call__(self, name):
+        @contextmanager
+        def ann():
+            self.opened.append(name)
+            yield
+            self.closed.append(name)
+        return ann()
+
+
+@pytest.fixture
+def annotations(monkeypatch):
+    from repro.obs import telemetry
+    ann = Annotations()
+    monkeypatch.setattr(telemetry, "_jax_annotation", ann)
+    return ann
+
+
+@pytest.mark.parametrize("name,profiler_name", [
+    ("stage", "dispatch/bucket2/stage"),
+    ("dispatch", "dispatch/bucket2")])
+def test_span_records_event_and_one_annotation(annotations, name,
+                                               profiler_name):
+    clock = FakeClock()
+    clock.advance(1.0)
+    tele = Telemetry(trace=True, annotate=True, clock=clock)
+    with tele.span(name, "bucket2", n=3):
+        assert annotations.opened == [profiler_name]
+        assert annotations.closed == []
+        clock.advance(0.25)
+    assert annotations.opened == annotations.closed == [profiler_name]
+    # the same event the recorder's own complete() records
+    assert tele.trace.events == [
+        {"ph": "X", "name": name, "cat": "serve", "tid": "bucket2",
+         "ts": 1.0, "dur": 0.25, "args": {"n": 3}}]
+
+
+def test_span_annotate_only_records_no_event(annotations):
+    tele = Telemetry(annotate=True)
+    with tele.span("fetch", "bucket0", bytes=8):
+        pass
+    assert annotations.opened == ["dispatch/bucket0/fetch"]
+    assert len(tele.trace) == 0
+
+
+def test_span_off_reads_no_clock_records_nothing(annotations):
+    reads = []
+
+    def clock():
+        reads.append(1)
+        return 0.0
+
+    tele = Telemetry(clock=clock)
+    a = tele.span("wait", "bucket0", why="ready")
+    b = tele.span("deliver", "bucket1", n=2)
+    assert a is b                                 # one shared null context
+    with a:
+        with b:
+            pass
+    assert reads == [] and len(tele.trace) == 0
+    assert annotations.opened == []
+
+
+class TickClock(FakeClock):
+    """A FakeClock that also moves 1 us on every read, so nested spans
+    get intervals of their own."""
+
+    def __call__(self):
+        self.t += 1e-6
+        return self.t
+
+
+def _spans(tele, lane):
+    return [e for e in tele.trace.events
+            if e["ph"] == "X" and e["tid"] == lane]
+
+
+def _inside(child, parent):
+    return (parent["ts"] <= child["ts"]
+            and child["ts"] + child["dur"] <= parent["ts"] + parent["dur"])
+
+
+def test_traced_request_phases_on_its_bucket_lane(lubm_served):
+    import time
+
     qs, part = lubm_served
-    srv = WorkloadServer(qs, part)
-    costs = srv.record_engine_costs()
-    assert len(costs["flops"]) == srv.n_buckets
-    reg = srv.telemetry.registry
-    for bi in range(srv.n_buckets):
-        assert reg["engine_flops"].get(bucket=str(bi)) == costs["flops"][bi]
-        assert reg["engine_bytes"].get(bucket=str(bi)) == costs["bytes"][bi]
-    assert all(f > 0 for f in costs["flops"])
+    clock = TickClock()
+    tele = Telemetry(trace=True, clock=clock)
+    srv = WorkloadServer(qs, part, answer_cache=False, telemetry=tele,
+                         pipeline=PipelineConfig(deadline_ms=0.0,
+                                                 clock=clock))
+    name = qs[0].name
+    lane = f"bucket{srv.route[name][0]}"
+    t = srv.submit(name)                          # the nested pump flushes
+    t_end = time.monotonic() + 60
+    while not t.done and time.monotonic() < t_end:
+        srv.pump()                                # retire once it is ready
+        time.sleep(0.001)
+    assert t.done and t.error is None
+    ev = {e["name"]: e for e in _spans(tele, lane)}
+    assert set(ev) == {"submit", "flush/deadline", "stage", "dispatch",
+                       "retire", "wait", "fetch", "extract", "deliver"}
+    # submit ends before the nested pump's flush begins
+    assert ev["submit"]["ts"] + ev["submit"]["dur"] <= \
+        ev["flush/deadline"]["ts"]
+    for child in ("stage", "dispatch"):
+        assert _inside(ev[child], ev["flush/deadline"])
+    order = ["wait", "fetch", "extract", "deliver"]
+    for a, b in zip(order, order[1:]):
+        assert _inside(ev[a], ev["retire"])
+        assert ev[a]["ts"] + ev[a]["dur"] <= ev[b]["ts"]
+    assert _inside(ev["deliver"], ev["retire"])
+    assert ev["wait"]["args"] == {"why": "ready"}
+    assert ev["fetch"]["args"]["bytes"] == srv.stats["d2h_bytes"] > 0
+    assert ev["extract"]["args"] == {"n": 1}
+    assert ev["deliver"]["args"] == {"n": 1}
+    assert ev["retire"]["args"] == {"n": 1, "epoch": 0}
+
+
+def test_wait_why_backpressure_and_drain(lubm_served):
+    qs, part = lubm_served
+    clock = FakeClock()
+    tele = Telemetry(trace=True, clock=clock)
+    srv = WorkloadServer(qs, part, answer_cache=False, telemetry=tele,
+                         pipeline=PipelineConfig(deadline_ms=None,
+                                                 max_inflight=1,
+                                                 clock=clock))
+    by_bucket = {}
+    for q in qs:
+        by_bucket.setdefault(srv.route[q.name][0], q.name)
+    (b0, n0), (b1, n1) = sorted(by_bucket.items())[:2]
+    srv.submit(n0, _pump=False)
+    srv.submit(n1, _pump=False)
+    srv.drain()
+    # the second flush exceeds max_inflight=1: the first waits for it
+    (w0,) = [e for e in _spans(tele, f"bucket{b0}") if e["name"] == "wait"]
+    (w1,) = [e for e in _spans(tele, f"bucket{b1}") if e["name"] == "wait"]
+    assert w0["args"] == {"why": "backpressure"}
+    assert w1["args"] == {"why": "drain"}
+
+
+def _fake_engine(bucket, live_rows):
+    """An engine stand-in returning a hand-built output for `bucket`: the
+    (batch, shard, table cap) mask holds `live_rows` true rows in every
+    row and shard of the batch."""
+    sig = bucket.signature
+    S, R, V = sig.n_shards, sig.table_cap, sig.n_vars
+
+    def fn(tr, va, perms, pd, params):
+        B = params.shape[0]
+        table = np.zeros((B, S, R, V), np.int32)
+        table[..., 0] = np.arange(R, dtype=np.int32)
+        tmask = np.zeros((B, S, R), bool)
+        tmask[..., :live_rows] = True
+        return table, tmask, np.zeros((B, S), bool)
+    return fn
+
+
+def test_extraction_and_fill_counters_on_hand_built_batch(lubm_served,
+                                                          monkeypatch):
+    qs, part = lubm_served
+    srv = WorkloadServer(qs, part, answer_cache=False, dedup=False,
+                         pipeline=PipelineConfig(deadline_ms=None))
+    name = qs[0].name
+    bi, _ = srv.route[name]
+    bucket = srv.buckets[bi]
+    fn = _fake_engine(bucket, live_rows=5)
+    monkeypatch.setattr(srv, "_engine", lambda b: fn)
+    out = fn(None, None, None, None, np.zeros((4, 1), np.int32))
+    srv.serve([(name, None)] * 3)                 # padded to 4: 1 filler
+    st = srv.stats
+    sig = bucket.signature
+    assert st["executed"] == 3
+    assert st["batch_rows_padded"] == 1
+    assert st["d2h_bytes"] == sum(a.nbytes for a in out)
+    assert st["table_rows_live"] == int(out[1][:3].sum()) \
+        == 3 * sig.n_shards * 5
+    assert st["table_rows_cap"] == 3 * sig.n_shards * sig.table_cap
+    # labelled by bucket
+    series = srv.telemetry.snapshot()["d2h_bytes"]["series"]
+    assert series == [{"labels": {"bucket": str(bi)},
+                       "value": st["d2h_bytes"]}]
+
+
+def test_fill_counters_match_the_real_engine_output(lubm_served):
+    qs, part = lubm_served
+    srv = WorkloadServer(qs, part, answer_cache=False)
+    srv.serve([(q.name, None) for q in qs])      # one row per template
+    st = srv.stats
+    want = {"table_rows_cap": 0, "d2h_bytes": 0, "batch_rows_padded": 0}
+    for bi, b in enumerate(srv.buckets):
+        n = sum(1 for q in qs if srv.route[q.name][0] == bi)
+        B = 1 << max(0, n - 1).bit_length()
+        S, R, V = (b.signature.n_shards, b.signature.table_cap,
+                   b.signature.n_vars)
+        want["table_rows_cap"] += n * S * R
+        # the padded batch's (R, V) int32 tables, bool masks and flags
+        want["d2h_bytes"] += B * S * (R * V * 4 + R + 1)
+        want["batch_rows_padded"] += B - n
+    assert {k: st[k] for k in want} == want
+    assert 0 < st["table_rows_live"] <= st["table_rows_cap"]
+
+
+# ---------------------------------------------------------------------------
+# named bucket programs
+# ---------------------------------------------------------------------------
+
+def _lowered(srv, bi):
+    from repro.engine.batch import pad_requests_pow2, stage_batch
+    st = srv._state
+    bucket = st.buckets[bi]
+    pd, params = stage_batch(bucket, pad_requests_pow2([(0, None)]),
+                             mesh=srv.mesh)
+    return srv._engine(bucket).lower(st.tr, st.va, st.perms, pd, params)
+
+
+@pytest.mark.parametrize("backend", ["jnp", "pallas"])
+def test_bucket_programs_named_by_signature(lubm_served, backend):
+    import re
+    qs, part = lubm_served
+    srv = WorkloadServer(qs, part, backend=backend)
+    names = []
+    for bi, b in enumerate(srv.buckets):
+        sig = b.signature
+        text = _lowered(srv, bi).as_text(debug_info=True)
+        (module,) = re.findall(r"module @(\S+)", text)
+        want = f"jit_kg_L{sig.n_steps}_V{sig.n_vars}_R{sig.table_cap}"
+        if backend != "jnp":
+            want += f"_{backend}"
+        assert module == want
+        names.append(module)
+        # every plan step's phases carry their scope
+        for i in range(sig.n_steps):
+            assert f"step{i}/scan" in text and f"step{i}/join" in text
+    assert len(set(names)) == len(names)          # one name per bucket
+
+
+def test_sharded_bucket_program_named_by_signature(lubm_small):
+    from repro.launch.mesh import make_engine_mesh
+    from repro.launch.serve import build_partition
+    qs = lubm_queries()
+    part = build_partition("centralized", lubm_small, qs, 1)
+    srv = WorkloadServer(qs, part, mesh=make_engine_mesh(1))
+    sig = srv.buckets[0].signature
+    text = _lowered(srv, 0).as_text()
+    assert (f"module @jit_kg_L{sig.n_steps}_V{sig.n_vars}_R{sig.table_cap} "
+            in text)
 
 
 def test_reset_stats_clears_counters_trace_not_state_gauges(lubm_served):
