@@ -51,8 +51,9 @@ from repro.engine.federated import (AXIS, ShardedKG, check_gather_cap,
 from repro.engine.planner import PhysicalPlan, pad_plan
 from repro.engine.primitives import (DEFAULT_BLOCKS, EQ_PAIRS, INT_MAX,
                                      KernelBlocks, check_backend,
-                                     compat_matrix, join_ranges, scan_hits,
-                                     select_cap, select_from_cum)
+                                     compat_matrix, join_ranges, rank_sites,
+                                     rank_sorted, scan_hits, select_cap,
+                                     select_from_cum)
 
 _EQ_PAIRS = EQ_PAIRS   # shared sentinels: one definition, engine/primitives
 _INT_MAX = INT_MAX
@@ -263,7 +264,8 @@ def _select_windows(n, width: int, cap: int):
     cum = jnp.cumsum(n)
     total = cum[-1]
     j = jnp.arange(cap, dtype=cum.dtype)
-    g = jnp.clip(jnp.searchsorted(cum, j, side="right"), 0, n.shape[0] - 1)
+    g, = rank_sorted(cum, j, "right")
+    g = jnp.clip(g, 0, n.shape[0] - 1)
     sel = j < total
     idx = jnp.where(sel, g * width + j - (cum[g] - n[g]),
                     n.shape[0] * width - 1)
@@ -281,7 +283,8 @@ def _select_rows(mask, cap: int):
     cum = jnp.cumsum(n)
     total = cum[-1]
     j = jnp.arange(min(cap, W * R), dtype=cum.dtype)
-    g = jnp.clip(jnp.searchsorted(cum, j, side="right"), 0, R - 1)
+    g, = rank_sorted(cum, j, "right")
+    g = jnp.clip(g, 0, R - 1)
     t = j - (cum[g] - n[g])                    # rank of slot j in row g
     w = jnp.sum(wcum[:, g] <= t[None, :], axis=0, dtype=cum.dtype)
     sel = j < total
@@ -382,7 +385,7 @@ def _join_merge(table, tmask, m_blocks, mm_blocks, pos0, kind, col,
                 backend: str = "jnp",
                 blocks: KernelBlocks = DEFAULT_BLOCKS):
     """Merge join against per-shard match blocks whose pos0 keys are sorted
-    (valid prefix) by construction — a binary search per block locates each
+    (valid prefix) by construction — a rank search per block locates each
     table row's candidate range, up to max_per_row candidates *per block* are
     expanded, and the remaining shared columns verify during expansion. No
     sort appears anywhere. Only traced for steps where every bucket member
@@ -509,14 +512,28 @@ def make_batched_engine(sig: BucketSignature, *, join_impl: str = "expand",
     engine composition (vmap batching, shard_map collectives, overflow
     flags) is backend-independent. kernel_blocks sets the kernels' tile
     sizes (a compile-cache key; see EngineCache).
+
+    The engine's `rank_sites` dict (carried by EngineCache's jitted
+    engines too) counts its rank searches by the method
+    `primitives.rank_method` picks on the default backend; it fills when
+    the engine is traced.
     """
     check_gather_cap(gather_cap)
     blocks = check_backend(backend, kernel_blocks)
     S, L, V, R = sig.n_shards, sig.n_steps, sig.n_vars, sig.table_cap
+    sites: dict[str, int] = {}
 
     def engine(triples: jax.Array, valid: jax.Array, perms: jax.Array,
                pd: PlanData, params: jax.Array):
-        """One request's plan interpreted against the (sharded) KG."""
+        """One request's plan interpreted against the (sharded) KG; each
+        trace records its rank searches' methods in `rank_sites`."""
+        with rank_sites() as traced:
+            out = interpret(triples, valid, perms, pd, params)
+        sites.clear()
+        sites.update(traced)
+        return out
+
+    def interpret(triples, valid, perms, pd, params):
         my = jax.lax.axis_index(axis_name) if S > 1 else jnp.int32(0)
         table = jnp.full((R, V), -1, jnp.int32)
         tmask = jnp.zeros((R,), bool).at[0].set(True)
@@ -603,6 +620,7 @@ def make_batched_engine(sig: BucketSignature, *, join_impl: str = "expand",
                     overflow = overflow | step_ovf | ovf_j
         return table, tmask, overflow
 
+    engine.rank_sites = sites
     return _named(engine, program_name(sig, backend))
 
 
@@ -653,7 +671,9 @@ def make_sharded_batched_engine(sig: BucketSignature, mesh, *,
         return (jnp.swapaxes(t, 0, 1), jnp.swapaxes(m, 0, 1),
                 jnp.swapaxes(o, 0, 1))
 
-    return jax.jit(_named(fn, engine.__name__))
+    jitted = jax.jit(_named(fn, engine.__name__))
+    jitted.rank_sites = engine.rank_sites
+    return jitted
 
 
 class EngineCache:
@@ -720,6 +740,7 @@ class EngineCache:
                     jax.vmap(engine, in_axes=(0, 0, 0, None, None),
                              axis_name=axis_name),           # shard axis
                     in_axes=(None, None, None, 0, 0)))       # batch axis
+                fn.rank_sites = engine.rank_sites
             self._fns[key] = fn
             while self.capacity is not None \
                     and len(self._fns) > self.capacity:

@@ -4,19 +4,24 @@ One home for the tensorized BGP building blocks that were previously
 copy-pasted between the per-query engine (`engine/local.py`: `scan_shard`,
 `join_step`) and the batched engine (`engine/batch.py`: `_scan_hit`,
 `_join_data`): the fused triple-pattern predicate, the cumsum-based stable
-compaction, the expand-join compatibility matrix, and the merge-join
-candidate-range search. Both engines now call these, so the jnp execution
-backend and the differential reference for the Pallas KG kernels
-(`kernels/kg_scan`, `kernels/kg_join`) are literally the same code.
+compaction, the expand-join compatibility matrix, the merge-join
+candidate ranges, and the rank search (searchsorted) under the last two.
+Both engines call these, and the scan and compat-matrix Pallas kernels
+(`kernels/kg_scan`, `kernels/kg_join`) use the jnp backend as their
+differential reference; the candidate-range kernel is held to numpy.
 
-Every function takes ``backend`` ("jnp" | "pallas"): "jnp" runs the dense
-XLA formulation below, "pallas" dispatches to the fused kernels. The two
-backends are bit-identical on every value that is ever read through a mask
-(hit masks, compaction index/selector triples, candidate ranges), which is
-what makes the engine-level differential guarantees possible.
+The scan and join functions take ``backend`` ("jnp" | "pallas"): "jnp"
+runs the dense XLA formulation below, "pallas" dispatches to the fused
+kernels. The two backends are bit-identical on every value that is ever
+read through a mask (hit masks, compaction index/selector triples,
+candidate ranges), which is what makes the engine-level differential
+guarantees possible.
 """
 from __future__ import annotations
 
+from collections import Counter
+from contextlib import contextmanager
+from contextvars import ContextVar
 from dataclasses import dataclass
 
 import jax
@@ -132,6 +137,77 @@ def scan_hits(triples, valid, spo, eq=None, *, backend: str = "jnp",
 
 
 # ---------------------------------------------------------------------------
+# rank search (searchsorted)
+# ---------------------------------------------------------------------------
+
+#: On an accelerator a rank search into sorted blocks of at most
+#: RANK_COMPARE_MAX_KEYS keys counts, for every query, the keys below it
+#: (`compare_all`: no gather, one fused reduce); past it, and on the CPU
+#: at every size, it binary-searches (`scan`). On a TPU v5e each
+#: binary-search level is a round of dependent gathers (about 10 ns a
+#: query) and a compare about 1.1 ps a key; XLA:CPU gathers cheaply
+#: (PERF.md, §6; `benchmarks/bench_rank.py` re-measures the crossover).
+RANK_COMPARE_MAX_KEYS = 131072
+
+_rank_log: ContextVar[Counter | None] = ContextVar("rank_log", default=None)
+
+
+def rank_method(n_keys: int, platform: str) -> str:
+    """The jnp.searchsorted method for a sorted block of `n_keys` keys on
+    `platform` ("cpu", "tpu", ...), from these static facts alone."""
+    if platform != "cpu" and n_keys <= RANK_COMPARE_MAX_KEYS:
+        return "compare_all"
+    return "scan"
+
+
+def _ranks(blocks, queries, sides, method: str):
+    """jnp.searchsorted of `queries` into each sorted row of `blocks` (B,
+    C), one (B, Q) array per side. "scan" binary-searches: ceil(log2(C +
+    1)) dependent rounds of one gathered key per query. "compare_all"
+    counts the keys below each query: C compares a query, no gather."""
+    return tuple(jax.vmap(lambda k, s=s: jnp.searchsorted(
+        k, queries, side=s, method=method))(blocks) for s in sides)
+
+
+@contextmanager
+def rank_sites():
+    """Count the rank searches traced inside the block by the method they
+    run on the default backend."""
+    log = Counter()
+    token = _rank_log.set(log)
+    try:
+        yield log
+    finally:
+        _rank_log.reset(token)
+
+
+def rank_sorted(keys, queries, *sides):
+    """jnp.searchsorted(keys, queries, side=s) for each side in `sides`,
+    integer for integer, by the method `rank_method` picks for the
+    platform the program is lowered for.
+
+    keys: (C,) sorted, or (B, C) with each block sorted; queries: (Q,), in
+    any order. Returns one int32 array per side, keys.shape[:-1] + (Q,)."""
+    blocks = keys if keys.ndim == 2 else keys[None]
+    n_keys = blocks.shape[1]
+    log = _rank_log.get()
+    if log is not None:
+        log[rank_method(n_keys, jax.default_backend())] += 1
+
+    def on(platform):
+        method = rank_method(n_keys, platform)
+
+        def ranks(blocks, queries):
+            with jax.named_scope(f"rank_{method}"):
+                return _ranks(blocks, queries, sides, method)
+        return ranks
+
+    ranks = jax.lax.platform_dependent(blocks, queries, cpu=on("cpu"),
+                                       default=on("tpu"))
+    return ranks if keys.ndim == 2 else tuple(r[0] for r in ranks)
+
+
+# ---------------------------------------------------------------------------
 # stable compaction
 # ---------------------------------------------------------------------------
 
@@ -143,8 +219,7 @@ def select_from_cum(cum, cap: int):
     n = cum.shape[0]
     k = min(cap, n)
     total = cum[-1]
-    idx = jnp.searchsorted(cum, jnp.arange(1, k + 1, dtype=jnp.int32),
-                           side="left")
+    idx, = rank_sorted(cum, jnp.arange(1, k + 1, dtype=cum.dtype), "left")
     idx = jnp.clip(idx, 0, n - 1)
     sel = jnp.arange(k) < total
     return idx, sel, total
@@ -152,7 +227,7 @@ def select_from_cum(cum, cap: int):
 
 def select_cap(mask, cap: int):
     """Stable compaction: (idx, sel, total) for the first `cap` set entries
-    of mask. Built from a cumsum plus a vectorized binary search — XLA:CPU
+    of mask. Built from a cumsum plus a vectorized rank search — XLA:CPU
     runs sort, top_k, and vmapped scatter at ~100-200ns/element, an order
     of magnitude slower than elementwise + gather ops, and this compaction
     runs once per plan step per (batch, shard) instance."""
@@ -220,18 +295,13 @@ def join_ranges(keys, rkey, *, backend: str = "jnp",
     """Merge-join candidate ranges: for sorted keys (per block) and table
     row keys rkey, return (lo, hi) with lo[.., r] = #{keys < rkey[r]} and
     hi[.., r] = #{keys <= rkey[r]} — exactly jnp.searchsorted left/right
-    on a sorted array. keys: (C,) or (S_b, C) int32 (invalid entries
-    INT_MAX-padded, which keeps them sorted); rkey: (R,) int32 < INT_MAX.
-    The "pallas" backend computes the counting formulation blocked over
-    (row, column) tiles — no binary search, no gathers — which is
-    integer-identical to searchsorted."""
+    on a sorted array (`rank_sorted` on the jnp backend). keys: (C,) or
+    (S_b, C) int32 (invalid entries INT_MAX-padded, which keeps them
+    sorted); rkey: (R,) int32 < INT_MAX. The "pallas" backend computes
+    the counting formulation blocked over (row, column) tiles — no binary
+    search, no gathers — which is integer-identical to searchsorted."""
     if backend == "pallas":
         from repro.kernels.kg_join.ops import join_ranges as pallas_ranges
         return pallas_ranges(keys, rkey, block_rows=blocks.join_rows,
                              block_cols=blocks.join_cols, interpret=interpret)
-    if keys.ndim == 1:
-        return (jnp.searchsorted(keys, rkey, side="left"),
-                jnp.searchsorted(keys, rkey, side="right"))
-    lo = jax.vmap(lambda k: jnp.searchsorted(k, rkey, side="left"))(keys)
-    hi = jax.vmap(lambda k: jnp.searchsorted(k, rkey, side="right"))(keys)
-    return lo, hi
+    return rank_sorted(keys, rkey, "left", "right")

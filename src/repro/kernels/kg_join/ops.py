@@ -49,7 +49,7 @@ def join_ranges(keys, rkey, *, block_rows: int = 256, block_cols: int = 512,
 
 
 def join_ranges_reference(keys, rkey):
-    return join_ranges_ref(jnp.asarray(keys), jnp.asarray(rkey))
+    return join_ranges_ref(keys, rkey)
 
 
 def compat_matrix(table, tmask, matches, mmask, kind, col, *,

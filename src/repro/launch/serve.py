@@ -437,10 +437,14 @@ class WorkloadServer:
     def _refresh_obs(self) -> None:
         """Re-publish the state gauges (epoch, per-bucket cut collectives)
         for the current serving state; called at init and on every epoch
-        bump since buckets can change count and signature."""
+        bump since buckets can change count and signature. A bucket's
+        `rank_sites` are published at its first dispatch of the epoch,
+        once its engine is traced."""
         tele = self.telemetry
         tele.gauge("epoch", self._state.epoch)
         tele.registry["cut_collectives"].clear()
+        tele.registry["rank_sites"].clear()
+        self._rank_published: set[int] = set()
         for bi, b in enumerate(self._state.buckets):
             tele.gauge("cut_collectives", bucket_collectives(b.signature),
                        bucket=str(bi))
@@ -1176,6 +1180,10 @@ class WorkloadServer:
                     return
             t_dispatch = self.pipeline.clock()
         tele.count("batch_rows_padded", n_pad, bucket=b_lab)
+        if bi not in self._rank_published:      # the engine is traced now
+            self._rank_published.add(bi)
+            for method, n in fn.rank_sites.items():
+                tele.gauge("rank_sites", n, bucket=b_lab, method=method)
         for t in take:
             t.t_dispatch = t_dispatch
             t.epoch = st.epoch

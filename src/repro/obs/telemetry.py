@@ -77,6 +77,8 @@ SERVING_SCHEMA: tuple[tuple, ...] = (
      "Current serving-state epoch."),
     ("cut_collectives", "gauge", ("bucket",),
      "Collectives per dispatch for the bucket == WawPart cut count."),
+    ("rank_sites", "gauge", ("bucket", "method"),
+     "Rank searches in the bucket's engine, by searchsorted method."),
     ("shard_requests", "gauge", ("shard",),
      "Requests in the tracker window touching the shard (live load)."),
     ("shard_load_imbalance", "gauge", (),

@@ -322,6 +322,38 @@ def test_cut_collective_gauges_match_signatures(lubm_served):
     assert got == [float(c) for c in srv.collective_counts()]
 
 
+def test_rank_site_gauges_match_the_traced_engines(lubm_served,
+                                                   monkeypatch):
+    """rank_sites{bucket, method} holds each dispatched bucket's rank
+    searches by method, as a fresh copy of its engine counts them when
+    traced; the rule is replaced by one that splits this scale's sites
+    between both methods on any platform."""
+    import jax
+
+    from repro.engine import primitives
+    from repro.engine.batch import EngineCache, assemble_batch, shard_perms
+    monkeypatch.setattr(primitives, "rank_method", lambda n_keys, platform:
+                        "compare_all" if n_keys > 1000 else "scan")
+    qs, part = lubm_served
+    srv = WorkloadServer(qs, part, answer_cache=False)
+    srv.serve(request_stream(qs, len(qs)))
+    got: dict = {}
+    for s in srv.telemetry.snapshot()["rank_sites"]["series"]:
+        got.setdefault(s["labels"]["bucket"], {})[s["labels"]["method"]] = \
+            s["value"]
+    kg, want = srv.kg, {}
+    for bi, b in enumerate(srv.buckets):
+        fn = EngineCache().get(b.signature, join_impl=srv.join_impl,
+                               max_per_row=srv.max_per_row,
+                               gather_cap=srv.gather_cap)
+        pd, params = assemble_batch(b, [(0, None)])
+        jax.eval_shape(fn, kg.triples, kg.valid, shard_perms(kg), pd, params)
+        want[str(bi)] = {m: float(n) for m, n in fn.rank_sites.items()}
+    assert got == want
+    assert {m for per in got.values() for m in per} == {"scan",
+                                                         "compare_all"}
+
+
 def test_invariant_self_check_fires_on_broken_counter(lubm_served):
     qs, part = lubm_served
     srv = WorkloadServer(qs, part, answer_cache=False,
@@ -515,6 +547,7 @@ def _fake_engine(bucket, live_rows):
         tmask = np.zeros((B, S, R), bool)
         tmask[..., :live_rows] = True
         return table, tmask, np.zeros((B, S), bool)
+    fn.rank_sites = {}                    # no traced rank search
     return fn
 
 

@@ -130,3 +130,20 @@ def test_bucket_engine_compiles(one_chip, smoke_server, native_kernels,
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < HBM_BYTES
     assert ("tpu_custom_call" in compiled.as_text()) == (backend == "pallas")
+
+
+def test_rank_search_compiles_gather_free(one_chip, smoke_server):
+    """The widest bucket's gathered merge steps (3 and 4): each of 3
+    vmapped shards ranks its table's row keys in 3 sorted match blocks.
+    The shape rule counts there, so no binary-search `while` loop is left."""
+    from repro.engine.primitives import rank_method, rank_sorted
+    sig = smoke_server.buckets[-1].signature       # the widest bucket
+    C, R = sig.scan_caps[3], sig.table_cap
+    assert rank_method(C, "tpu") == "compare_all"
+    text = _compile(
+        jax.vmap(lambda k, r: rank_sorted(k, r, "left", "right")),
+        _shape(one_chip, (N_SHARDS, N_SHARDS, C), jnp.int32),
+        _shape(one_chip, (N_SHARDS, R), jnp.int32))
+    loops = [ln for ln in text.splitlines() if " while(" in ln]
+    assert not [ln for ln in loops if "searchsorted" in ln]
+    assert "rank_compare_all" in text
