@@ -10,6 +10,12 @@ Everything a cell needs is found by name, from ``BENCHMARK.json``:
 - each per-layer metric is read by ``<bench>/metrics/<metric>.py``, a module
   with ``read(record) -> float | None``.
 
+So a new cell is new files and new entries of ``BENCHMARK.json``. Its
+configuration and traffic files each carry a ``"cpu"`` block, the sizes at
+which the CPU tests run them (testkit.py); nothing here reads it. `resolve`
+refuses a cell whose ``chips`` differs from its configuration's, or, under
+the ``mesh`` placement, from its shard count.
+
 The program is reached only through its public entry points:
 ``TripleStore.from_string_triples``, ``build_partition``,
 ``WorkloadServer`` with ``submit``/``pump``/``drain``/``warmup``/
@@ -77,6 +83,7 @@ def resolve(cell_name: str, root: Path = REPO) -> Cell:
     w = cells[cell_name]
     cfg_entry = {c["name"]: c for c in bench["configs"]}[w["config"]]
     config = json.loads((root / cfg_entry["file"]).read_text())
+    check_chips(cell_name, int(w["chips"]), config)
     queries = json.loads(
         (bdir / "queries" / f"{config['queries']}.json").read_text())
     templates = {t["name"]: [tuple(p) for p in t["patterns"]]
@@ -94,6 +101,20 @@ def resolve(cell_name: str, root: Path = REPO) -> Cell:
                                            / f"{m['name']}.py")
                     for m in per_layer}
     return cell
+
+
+def check_chips(cell_name: str, chips: int, config: dict) -> None:
+    """Raises ValueError where the cell's chips, its configuration's chips
+    and, for the mesh placement, its shard count disagree: the shard_map
+    path holds one shard on each device."""
+    if chips != int(config["chips"]):
+        raise ValueError(f"cell {cell_name!r} asks for {chips} chip(s); its "
+                         f"configuration states {config['chips']}")
+    shards = int(config["partition"]["shards"])
+    if config["placement"] == "mesh" and shards != chips:
+        raise ValueError(f"cell {cell_name!r}: the mesh placement needs one "
+                         f"chip per shard; {shards} shards on {chips} "
+                         f"chip(s)")
 
 
 # ---------------------------------------------------------------------------
